@@ -16,6 +16,7 @@ Usage:
         ...                       # complete event "X" with measured dur
     trace.instant("evict", sid=3, cost_bytes=1892)
     trace.counter("parking", parked=7, bound=16)
+    trace.complete("plane_op", t_enqueue_ns, t_done_ns, cat="plane", id=9)
     trace.export("trace.json")
 
 A DISABLED tracer (the default) costs one attribute load and a truthiness
@@ -26,6 +27,12 @@ hot path pays nothing measurable.  Activation:
     at import time and registers an atexit export to that path (how the CI
     bench job captures its trace artifact);
   * ``Tracer(enabled=True)`` / ``tracer.enable()`` — programmatic.
+
+``Tracer(annotate=True)`` also enters a ``jax.profiler.TraceAnnotation``
+for every ``span()``, so an XProf profile of a live server shows the
+plane's and the services' spans on the device trace's clock and names
+device idle gaps by them.  ``complete()`` events are never annotated:
+they are recorded after the fact and overlap each other.
 
 Services accept a ``tracer=`` argument and default to the global one, so
 a test can hand a private enabled tracer to one service without touching
@@ -59,26 +66,32 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = _annotation(name) if tracer.annotate else None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        self._tracer._push({
-            "name": self.name, "ph": "X", "cat": self.cat or "repro",
-            "ts": self._t0 / 1e3, "dur": (t1 - self._t0) / 1e3,
-            "pid": self._tracer.pid, "tid": self._tracer._tid(),
-            "args": self.args})
+        self._tracer.complete(self.name, self._t0, time.perf_counter_ns(),
+                              self.cat, **self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -88,11 +101,14 @@ class Tracer:
     ``ts``/``dur`` are microseconds on the ``time.perf_counter_ns`` clock
     (monotonic; one clock for every event, so spans nest correctly in the
     viewer).  Thread identity comes from ``threading.get_native_id`` so a
-    future multi-worker front-end traces onto separate rows for free."""
+    future multi-worker front-end traces onto separate rows for free.
+    ``annotate=True`` mirrors every ``span()`` into the JAX profiler
+    (see the module docstring)."""
 
     def __init__(self, *, enabled: bool = False, capacity: int = 65536,
-                 pid: int | None = None):
+                 pid: int | None = None, annotate: bool = False):
         self.enabled = enabled
+        self.annotate = annotate
         self.pid = os.getpid() if pid is None else pid
         self._events: deque = deque(maxlen=capacity)
         self.dropped = 0  # events that fell off the ring
@@ -118,6 +134,18 @@ class Tracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
+
+    def complete(self, name: str, t0_ns: int, t1_ns: int, cat: str = "",
+                 **args) -> None:
+        """Record a complete ("X") event after the fact, from ``t0_ns`` to
+        ``t1_ns`` on the ``time.perf_counter_ns`` clock — for an extent
+        whose ends are stamped in different places (an op's life from
+        enqueue to the end of the cycle that served it)."""
+        if not self.enabled:
+            return
+        self._push({"name": name, "ph": "X", "cat": cat or "repro",
+                    "ts": t0_ns / 1e3, "dur": (t1_ns - t0_ns) / 1e3,
+                    "pid": self.pid, "tid": self._tid(), "args": args})
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         """Zero-duration marker ("i" event) — scheduler decisions (evict,

@@ -184,6 +184,11 @@ class _Op:
     args: tuple = ()             # open_session positional args
     kwargs: dict = field(default_factory=dict)
     deadline: float | None = None  # absolute monotonic; checked at dequeue
+    # trace stamps (perf_counter_ns), set only while the tracer is enabled
+    trace_id: int = 0
+    t_enq: int = 0
+    t_deq: int = 0
+    t_done: int = 0
 
 
 class _Worker:
@@ -286,6 +291,8 @@ class ServingPlane:
                           for w in self.workers]
         self._sessions: dict[int, tuple[_Worker, int]] = {}  # psid -> (w, sid)
         self._next_psid = 0
+        self._n_ops = 0        # traced ops enqueued (their ``plane_op`` id)
+        self._n_cycles = 0     # scheduling cycles run (the ``batch`` id)
         self._running = False
         # fault-tolerance state: the per-session spill journal, the
         # per-(worker, service tid) tenant-state journal, and explicit
@@ -564,6 +571,10 @@ class ServingPlane:
             else self.default_deadline_s
         if deadline_s is not None:
             op.deadline = time.monotonic() + deadline_s
+        if self.tracer.enabled:
+            self._n_ops += 1
+            op.trace_id = self._n_ops
+            op.t_enq = time.perf_counter_ns()
         w.queue.append(op)
         self._g_depth[w.idx].set(len(w.queue))
         w.wake.set()
@@ -590,15 +601,31 @@ class ServingPlane:
 
     def _cycle(self, w: _Worker) -> None:
         """One scheduling cycle: execute the longest FIFO prefix of the
-        queue that fits a single grid dispatch (see module docstring)."""
+        queue that fits a single grid dispatch (see module docstring).
+        While the tracer is enabled, every op the cycle takes off the
+        queue is recorded as one ``plane_op`` event (``_trace_ops``)."""
+        self._n_cycles += 1
+        traced = self.tracer.enabled
+        taken: list[_Op] = []
+        try:
+            self._run_cycle(w, taken if traced else None)
+        finally:
+            if traced:
+                self._trace_ops(taken)
+
+    def _run_cycle(self, w: _Worker, taken: list[_Op] | None) -> None:
         svc = w.service
         t0 = time.monotonic()
         n_ops = 0
         batch: dict[int, Any] = {}
         futs: dict[int, asyncio.Future] = {}
         psids: dict[int, int] = {}
+        pushed: list[_Op] = []
         while w.queue:
             op = w.queue[0]
+            if taken is not None:
+                op.t_deq = time.perf_counter_ns()
+                taken.append(op)
             if op.fut.done():        # client cancelled while queued
                 w.queue.popleft()
                 continue
@@ -611,16 +638,24 @@ class ServingPlane:
                 continue
             if op.kind == "push":
                 if op.sid in batch or len(batch) >= svc.n_slots:
+                    if taken is not None:
+                        taken.pop()  # stays queued for the next cycle
                     break            # cut: would break FIFO or overflow grid
                 w.queue.popleft()
                 batch[op.sid] = op.work
                 futs[op.sid] = op.fut
                 psids[op.sid] = op.psid
+                if taken is not None:
+                    pushed.append(op)
             else:
                 if op.sid is not None and op.sid in batch:
+                    if taken is not None:
+                        taken.pop()
                     break            # control on a batched sid: after dispatch
                 w.queue.popleft()
                 self._do_control(w, op)
+                if taken is not None:
+                    op.t_done = time.perf_counter_ns()
                 n_ops += 1
                 if w.health == CRASHED:
                     # the queue was failed by _on_crash; lanes already cut
@@ -635,9 +670,36 @@ class ServingPlane:
         if batch:
             self._dispatch(w, batch, futs, psids)
             n_ops += len(batch)
+            if taken is not None:
+                t_done = time.perf_counter_ns()
+                for op in pushed:
+                    op.t_done = t_done
         if n_ops:
             dt = (time.monotonic() - t0) / n_ops
             w.ema_op_s += 0.2 * (dt - w.ema_op_s)
+
+    def _trace_ops(self, ops: list[_Op]) -> None:
+        """One ``plane_op`` event per op the cycle took off the queue, from
+        its enqueue to the end of the cycle, when the worker yields and
+        the client can resume: ``queue_us`` is the wait from enqueue to
+        dequeue, ``held_us`` the ack delay from the op's future being
+        resolved (for an op refused at dequeue, from its dequeue) to the
+        cycle's end.  ``batch`` joins the op to the cycle's
+        ``plane_batch`` span and the service spans nested in it.  An op
+        that expired, was cancelled in the queue or failed has
+        ``ok=False``."""
+        t_end = time.perf_counter_ns()
+        for op in ops:
+            if not op.trace_id:      # enqueued before the tracer was on
+                continue
+            fut = op.fut
+            ok = (fut.done() and not fut.cancelled()
+                  and fut.exception() is None)
+            self.tracer.complete(
+                "plane_op", op.t_enq, t_end, cat="plane", id=op.trace_id,
+                kind=op.kind, batch=self._n_cycles,
+                queue_us=(op.t_deq - op.t_enq) / 1e3,
+                held_us=(t_end - (op.t_done or op.t_deq)) / 1e3, ok=ok)
 
     def _do_control(self, w: _Worker, op: _Op) -> None:
         svc = w.service
@@ -718,7 +780,8 @@ class ServingPlane:
         self._h_lanes.record(len(live))
         try:
             with self.tracer.span("plane_batch", cat="plane",
-                                  worker=w.idx, lanes=len(live)):
+                                  worker=w.idx, lanes=len(live),
+                                  batch=self._n_cycles):
                 out = w.service.push(live)
         except WorkerCrashed:
             for sid in live:

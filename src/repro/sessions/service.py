@@ -250,9 +250,6 @@ class SlotGridService:
         self._latency_hist(shape).record(seconds * 1e6)
         self._g_bound.set(len(self.sched.slot_of))
         self._g_parked.set(len(self.parking))
-        self.tracer.counter(f"{self._service_name}_sessions",
-                            bound=len(self.sched.slot_of),
-                            parked=len(self.parking))
 
     def _ingest_occupancy(self, stats) -> None:
         """Fold one dispatch's device-side occupancy vector
@@ -332,7 +329,9 @@ class SlotGridService:
                 self.tracer.instant("resume", cat=self._service_name,
                                     sid=sid, slot=slot)
             elif self.sessions[sid].steps == 0:
-                self._reset(slot)
+                with self.tracer.span("reset", cat=self._service_name,
+                                      sid=sid, slot=slot):
+                    self._reset(slot)
             else:  # rebinding after evicted==None cannot lose state
                 raise AssertionError("bound session missing parked state")
             self._on_bind(sid, slot)
@@ -800,12 +799,14 @@ class StreamSessionService(SlotGridService):
                 and tenant in self.bankpool.n_ways
                 and self.bankpool.is_resident(tenant)
                 and self._tenant_idle(tenant)):
-            self.bankpool.park(tenant)
+            with self.tracer.span("bank_park", cat="tcn", tenant=tenant):
+                self.bankpool.park(tenant)
             self._update_pool_gauges()
 
     def _ensure_bank_resident(self, tenant: int) -> None:
         if self.paged_bank and not self.bankpool.is_resident(tenant):
-            self.bankpool.unpark(tenant)  # may raise PoolExhausted
+            with self.tracer.span("bank_unpark", cat="tcn", tenant=tenant):
+                self.bankpool.unpark(tenant)  # may raise PoolExhausted
             self._update_pool_gauges()
 
     def _update_pool_gauges(self) -> None:
@@ -945,6 +946,94 @@ class StreamSessionService(SlotGridService):
         tenant_logits (t_i, ways) | None — plus the end-of-chunk
         classification ``pred`` and cumulative ``step``.  For a (C_in,)
         sample the historical surface is kept: emb (V,), logits (n,)."""
+        tr = self.tracer
+        with tr.span("prepare", cat="tcn", lanes=len(chunks)):
+            arrs, scalar = self._validate_chunks(chunks)
+            self._touch_and_bind(chunks)
+            if self.paged_bank:
+                # every pushed session's tenant must hold its bank rows on
+                # device before the dispatch reads them through the tables
+                for sid in arrs:
+                    t = self.sessions[sid].tenant
+                    if t != NO_TENANT:
+                        self._ensure_bank_resident(t)
+            slot_of = {sid: self.sched.slot_of[sid] for sid in arrs}
+            lens = {sid: a.shape[0] for sid, a in arrs.items()}
+            max_len = max(lens.values())
+            tick = self._tick_inputs(arrs, slot_of, 0, max_len)
+        pieces = {sid: [] for sid in arrs}  # per-tick (emb, logits, tl) slices
+        off = 0
+        while True:
+            t_pad, x, tick_lens, bank_args = tick
+            shape = f"T{t_pad}"
+            dev_stats = None
+            t0 = time.perf_counter()
+            with tr.span("dispatch", cat="tcn", shape=shape,
+                         lanes=len(arrs), fused=self.fused):
+                with tr.span("launch", cat="tcn"):
+                    if self.fused:
+                        self.states, emb, logits, tlogits, *dev = \
+                            self._scan_fused(
+                                self._fused_params, self.states,
+                                jnp.asarray(x), jnp.asarray(tick_lens),
+                                *bank_args)
+                    else:
+                        valid = np.arange(t_pad)[None, :] < tick_lens[:, None]
+                        self.states, emb, logits, tlogits, *dev = self._scan(
+                            self._params, self._bn, self.states,
+                            jnp.asarray(x), jnp.asarray(valid), *bank_args)
+                if tr.enabled:   # the device's time apart from the copies
+                    with tr.span("device_wait", cat="tcn"):
+                        jax.block_until_ready((emb, logits, tlogits, dev))
+                with tr.span("fetch", cat="tcn"):
+                    emb, logits, tlogits = (np.asarray(emb),
+                                            np.asarray(logits),
+                                            np.asarray(tlogits))
+                    if self.paged_bank:
+                        # table width is block-granular (>= max_ways); keep
+                        # the result surface mode-independent
+                        tlogits = tlogits[..., :self.max_ways]
+                    if dev:
+                        dev_stats = np.asarray(dev[0])
+            self._record_dispatch(time.perf_counter() - t0, shape)
+            if dev_stats is not None:
+                self._ingest_occupancy(dev_stats)
+            for sid in arrs:
+                n = min(max(lens[sid] - off, 0), t_pad)
+                if n:
+                    s = slot_of[sid]
+                    pieces[sid].append(
+                        (emb[s, :n], logits[s, :n], tlogits[s, :n]))
+            off += t_pad
+            if off >= max_len:
+                break
+            with tr.span("prepare", cat="tcn", lanes=len(arrs)):
+                tick = self._tick_inputs(arrs, slot_of, off, max_len)
+
+        with tr.span("assemble", cat="tcn", lanes=len(arrs)):
+            out = {}
+            for sid in arrs:
+                sess = self.sessions[sid]
+                sess.steps += lens[sid]
+                e, l, tl = (np.concatenate([p[i] for p in pieces[sid]])
+                            for i in range(3))
+                personalized = (sess.tenant != NO_TENANT
+                                and self._tenant_ways[sess.tenant] > 0)
+                head = tl if personalized else l
+                if scalar[sid]:
+                    res = {"emb": e[-1], "logits": l[-1],
+                           "tenant_logits": tl[-1] if personalized else None,
+                           "pred": int(head[-1].argmax()), "step": sess.steps}
+                else:
+                    res = {"emb": e, "logits": l,
+                           "tenant_logits": tl if personalized else None,
+                           "pred": int(head[-1].argmax()), "step": sess.steps}
+                sess.last = res
+                out[sid] = res
+        return out
+
+    def _validate_chunks(self, chunks: dict[int, Any]):
+        """({sid: (t, C_in) float32 chunk}, {sid: pushed as one sample})."""
         if len(chunks) > self.n_slots:
             raise ValueError(
                 f"{len(chunks)} sessions pushed but only {self.n_slots} slots; "
@@ -963,90 +1052,28 @@ class StreamSessionService(SlotGridService):
             if a.shape[0] == 0:
                 raise ValueError(f"session {sid}: empty chunk")
             arrs[sid] = a
-        self._touch_and_bind(chunks)
+        return arrs, scalar
+
+    def _tick_inputs(self, arrs: dict, slot_of: dict, off: int,
+                     max_len: int):
+        """One tick's dispatch inputs from sample ``off`` on: (tick length,
+        x (S, T, C_in), per-slot lengths, bank arguments)."""
+        t_pad = self._tick_len(max_len - off)
+        x = np.zeros((self.n_slots, t_pad, self.cfg.tcn_in_channels),
+                     np.float32)
+        tick_lens = np.zeros(self.n_slots, np.int32)
+        for sid, a in arrs.items():
+            seg = a[off:off + t_pad]
+            if seg.shape[0]:
+                x[slot_of[sid], :seg.shape[0]] = seg
+                tick_lens[slot_of[sid]] = seg.shape[0]
         if self.paged_bank:
-            # every pushed session's tenant must hold its bank rows on
-            # device before the dispatch reads them through the tables
-            for sid in arrs:
-                t = self.sessions[sid].tenant
-                if t != NO_TENANT:
-                    self._ensure_bank_resident(t)
-
-        slot_of = {sid: self.sched.slot_of[sid] for sid in arrs}
-        lens = {sid: a.shape[0] for sid, a in arrs.items()}
-        max_len = max(lens.values())
-        pieces = {sid: [] for sid in arrs}  # per-tick (emb, logits, tl) slices
-        off = 0
-        while off < max_len:
-            t_pad = self._tick_len(max_len - off)
-            x = np.zeros((self.n_slots, t_pad, c_in), np.float32)
-            tick_lens = np.zeros(self.n_slots, np.int32)
-            for sid, a in arrs.items():
-                seg = a[off:off + t_pad]
-                if seg.shape[0]:
-                    x[slot_of[sid], :seg.shape[0]] = seg
-                    tick_lens[slot_of[sid]] = seg.shape[0]
-            shape = f"T{t_pad}"
-            dev_stats = None
-            if self.paged_bank:
-                tables, ways = self.bankpool.slot_tables(self.tenant_of_slot)
-                bank_args = (self.bankpool.s_sums, self.bankpool.counts,
-                             jnp.asarray(tables), jnp.asarray(ways))
-            else:
-                bank_args = (self.bank, jnp.asarray(self.tenant_of_slot))
-            t0 = time.perf_counter()
-            with self.tracer.span("dispatch", cat="tcn", shape=shape,
-                                  lanes=len(arrs),
-                                  fused=self.fused):
-                if self.fused:
-                    self.states, emb, logits, tlogits, *dev = \
-                        self._scan_fused(
-                            self._fused_params, self.states, jnp.asarray(x),
-                            jnp.asarray(tick_lens), *bank_args)
-                else:
-                    valid = np.arange(t_pad)[None, :] < tick_lens[:, None]
-                    self.states, emb, logits, tlogits, *dev = self._scan(
-                        self._params, self._bn, self.states, jnp.asarray(x),
-                        jnp.asarray(valid), *bank_args)
-                emb, logits, tlogits = (np.asarray(emb), np.asarray(logits),
-                                        np.asarray(tlogits))
-                if self.paged_bank:
-                    # table width is block-granular (>= max_ways); keep
-                    # the result surface mode-independent
-                    tlogits = tlogits[..., :self.max_ways]
-                if dev:
-                    dev_stats = np.asarray(dev[0])
-            self._record_dispatch(time.perf_counter() - t0, shape)
-            if dev_stats is not None:
-                self._ingest_occupancy(dev_stats)
-            for sid in arrs:
-                n = min(max(lens[sid] - off, 0), t_pad)
-                if n:
-                    s = slot_of[sid]
-                    pieces[sid].append(
-                        (emb[s, :n], logits[s, :n], tlogits[s, :n]))
-            off += t_pad
-
-        out = {}
-        for sid in arrs:
-            sess = self.sessions[sid]
-            sess.steps += lens[sid]
-            e, l, tl = (np.concatenate([p[i] for p in pieces[sid]])
-                        for i in range(3))
-            personalized = (sess.tenant != NO_TENANT
-                            and self._tenant_ways[sess.tenant] > 0)
-            head = tl if personalized else l
-            if scalar[sid]:
-                res = {"emb": e[-1], "logits": l[-1],
-                       "tenant_logits": tl[-1] if personalized else None,
-                       "pred": int(head[-1].argmax()), "step": sess.steps}
-            else:
-                res = {"emb": e, "logits": l,
-                       "tenant_logits": tl if personalized else None,
-                       "pred": int(head[-1].argmax()), "step": sess.steps}
-            sess.last = res
-            out[sid] = res
-        return out
+            tables, ways = self.bankpool.slot_tables(self.tenant_of_slot)
+            bank_args = (self.bankpool.s_sums, self.bankpool.counts,
+                         jnp.asarray(tables), jnp.asarray(ways))
+        else:
+            bank_args = (self.bank, jnp.asarray(self.tenant_of_slot))
+        return t_pad, x, tick_lens, bank_args
 
     # protocol verb (sessions.SessionService): the TCN payload is audio
     push = push_audio
@@ -1078,44 +1105,21 @@ class StreamSessionService(SlotGridService):
         if tenant == NO_TENANT:
             raise ValueError("session has no tenant; open with tenant=None "
                              "or an explicit tenant id to personalize")
-        with self.tracer.span("enroll", cat="tcn", sid=sid, tenant=tenant):
-            emb = jnp.asarray(shots) if embedded \
-                else self._embed(jnp.asarray(shots))
-            if label is not None:
-                if way is not None:
-                    raise ValueError("pass way= or label=, not both")
-                way = self._tenant_labels.setdefault(tenant, {}).get(label)
-            if way is None:
-                if self._tenant_ways[tenant] >= self.max_ways:
-                    raise RuntimeError(
-                        f"tenant {tenant} at max_ways={self.max_ways}")
-                if self.paged_bank:
-                    self._ensure_bank_resident(tenant)
-                    way = self.bankpool.add_class(tenant, emb)
-                    self._update_pool_gauges()
-                else:
-                    self.bank = bank_add_class(self.bank, tenant, emb)
-                    way = int(self._tenant_ways[tenant])
-                self._tenant_ways[tenant] += 1
-                if label is not None:
-                    self._tenant_labels[tenant][label] = way
-            else:
-                if not 0 <= way < self._tenant_ways[tenant]:
-                    raise ValueError(
-                        f"way {way} not enrolled for tenant {tenant} "
-                        f"({self._tenant_ways[tenant]} ways); omit way= to "
-                        "enroll")
-                if self.paged_bank:
-                    self._ensure_bank_resident(tenant)
-                    self.bankpool.update_class(tenant, way, emb)
-                else:
-                    self.bank = bank_update_class(self.bank, tenant, way, emb)
-            if self.rehearsal is not None:
-                self.rehearsal.add(tenant, way, np.asarray(emb))
-                self._g_rehearsal_bytes.set(self.rehearsal.nbytes())
+        tr = self.tracer
+        with tr.span("enroll", cat="tcn", sid=sid, tenant=tenant):
+            with tr.span("embed", cat="tcn", embedded=embedded):
+                emb = jnp.asarray(shots) if embedded \
+                    else self._embed(jnp.asarray(shots))
+                if tr.enabled:
+                    # the embedding's device time in this span; untraced,
+                    # the bank update's host work overlaps it
+                    jax.block_until_ready(emb)
+            with tr.span("bank_update", cat="tcn"):
+                way = self._bank_update(tenant, emb, way, label)
             # honest latency: the bank update must have landed on device
-            jax.block_until_ready(
-                self.bankpool.s_sums if self.paged_bank else self.bank.s_sums)
+            with tr.span("device_wait", cat="tcn"):
+                jax.block_until_ready(self.bankpool.s_sums if self.paged_bank
+                                      else self.bank.s_sums)
             self._maybe_park_tenant(tenant)
         self._c_enrolls.inc()
         self._c_enroll_shots.inc(int(np.asarray(shots).shape[0]))
@@ -1124,6 +1128,44 @@ class StreamSessionService(SlotGridService):
 
     # protocol verb (sessions.SessionService): learning is first-class
     enroll = enroll_shots
+
+    def _bank_update(self, tenant: int, emb, way: int | None, label) -> int:
+        """Fold (k, V) shot embeddings into the tenant's bank (a new way,
+        or a running-mean refinement of ``way`` or ``label``'s way); keeps
+        the rehearsal buffer in step.  Returns the way."""
+        if label is not None:
+            if way is not None:
+                raise ValueError("pass way= or label=, not both")
+            way = self._tenant_labels.setdefault(tenant, {}).get(label)
+        if way is None:
+            if self._tenant_ways[tenant] >= self.max_ways:
+                raise RuntimeError(
+                    f"tenant {tenant} at max_ways={self.max_ways}")
+            if self.paged_bank:
+                self._ensure_bank_resident(tenant)
+                way = self.bankpool.add_class(tenant, emb)
+                self._update_pool_gauges()
+            else:
+                self.bank = bank_add_class(self.bank, tenant, emb)
+                way = int(self._tenant_ways[tenant])
+            self._tenant_ways[tenant] += 1
+            if label is not None:
+                self._tenant_labels[tenant][label] = way
+        else:
+            if not 0 <= way < self._tenant_ways[tenant]:
+                raise ValueError(
+                    f"way {way} not enrolled for tenant {tenant} "
+                    f"({self._tenant_ways[tenant]} ways); omit way= to "
+                    "enroll")
+            if self.paged_bank:
+                self._ensure_bank_resident(tenant)
+                self.bankpool.update_class(tenant, way, emb)
+            else:
+                self.bank = bank_update_class(self.bank, tenant, way, emb)
+        if self.rehearsal is not None:
+            self.rehearsal.add(tenant, way, np.asarray(emb))
+            self._g_rehearsal_bytes.set(self.rehearsal.nbytes())
+        return way
 
     def rehearse_tenant(self, tenant: int) -> int:
         """Rebuild every enrolled way of ``tenant`` from the bounded

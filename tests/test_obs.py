@@ -3,8 +3,10 @@ device-side counters, and — the load-bearing contract — bit-identity of
 instrumented services vs uninstrumented ones on every state leaf and
 every emitted output."""
 
+import asyncio
 import functools
 import json
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,7 @@ from repro.obs import (
     valid_stats,
 )
 from repro.obs.metrics import Histogram
+from repro.serving import Rejected, ServingPlane
 from repro.sessions import (
     LMSessionService,
     SpeculativeDecoder,
@@ -156,6 +159,52 @@ def test_trace_ring_buffer_drops_oldest():
     assert t.dropped == 6
     t.clear()
     assert t.events() == [] and t.dropped == 0
+
+
+def test_complete_records_given_extent_only_when_enabled():
+    t = Tracer()
+    t.complete("plane_op", 1_000, 5_000, cat="plane", id=1)
+    assert t.events() == []
+    t.enable()
+    t.complete("plane_op", 1_000, 5_000, cat="plane", id=1, ok=True)
+    (e,) = t.events()
+    assert (e["name"], e["ph"], e["cat"]) == ("plane_op", "X", "plane")
+    assert (e["ts"], e["dur"]) == (1.0, 4.0)
+    assert e["args"] == {"id": 1, "ok": True}
+
+
+def test_annotate_mirrors_spans_into_the_profiler(monkeypatch):
+    """``annotate=True`` enters one profiler annotation per span, around
+    it; the default and ``complete()`` events enter none."""
+    trace_mod = sys.modules[Tracer.__module__]
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod, "_annotation", Ann)
+    t = Tracer(enabled=True, annotate=True)
+    with t.span("dispatch"):
+        with t.span("launch"):
+            pass
+    t.complete("plane_op", 0, 10)
+    assert seen == [("enter", "dispatch"), ("enter", "launch"),
+                    ("exit", "launch"), ("exit", "dispatch")]
+    assert [e["name"] for e in t.events()] == ["launch", "dispatch",
+                                                "plane_op"]
+    seen.clear()
+    with Tracer(enabled=True).span("dispatch"):
+        pass
+    with Tracer(annotate=True).span("dispatch"):   # disabled: no-op
+        pass
+    assert seen == []
 
 
 # ---------------------------------------------------------------------------
@@ -367,3 +416,132 @@ def test_park_unknown_sid_raises():
     lm = LMSessionService(bundle2, params2, n_slots=2, seq_cap=32)
     with pytest.raises(KeyError):
         lm.park(123)
+
+
+# ---------------------------------------------------------------------------
+# a request traced through the plane and the service
+# ---------------------------------------------------------------------------
+
+N_CLIENTS, PUSH_LENS = 4, (5, 8, 11)    # 11 samples: a T8 and a T4 tick
+
+
+def _plane_run(tracer):
+    """Four clients over a traced plane on a paged-bank service: open a
+    session of a tenant, enroll raw shots, push three ragged chunks.  Then
+    tenant 0's two sessions park (its bank rows leave the device), one of
+    them pushes again (they come back), and one more push expires in the
+    queue.  Returns (results, service, events)."""
+    cfg, bundle, params, bn = _tcn_setup()
+    reg = MetricsRegistry()
+    svc = StreamSessionService(bundle, params, bn, n_slots=4, max_tenants=2,
+                               max_ways=4, t_chunk=8, paged_bank=True,
+                               bank_block_ways=2, metrics=reg, tracer=tracer)
+    rng = np.random.default_rng(7)
+    shots = rng.standard_normal((N_CLIENTS, 2, 6, 2)).astype(np.float32)
+    chunks = [[rng.standard_normal((n, 2)).astype(np.float32)
+               for n in PUSH_LENS] for _ in range(N_CLIENTS)]
+
+    async def client(plane, c):
+        psid = await plane.open_session(tenant=c % 2)
+        out = [psid, await plane.enroll(psid, shots[c], label=c)]
+        for x in chunks[c]:
+            out.append(await plane.push(psid, x))
+        return out
+
+    async def main():
+        async with ServingPlane(svc, metrics=reg, tracer=tracer) as plane:
+            res = await asyncio.gather(*(client(plane, c)
+                                         for c in range(N_CLIENTS)))
+            await plane.park(res[0][0])
+            await plane.park(res[2][0])
+            res[0].append(await plane.push(res[0][0], chunks[1][2]))
+            with pytest.raises(Rejected):
+                await plane.push(res[0][0], chunks[0][0], deadline_s=-1.0)
+            return res
+
+    return asyncio.run(main()), svc, tracer.events()
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+def test_plane_records_one_op_event_per_request():
+    _, _, evs = _plane_run(Tracer(enabled=True))
+    ops = [e for e in evs if e["name"] == "plane_op"]
+    per_client = 2 + len(PUSH_LENS)               # open, enroll, pushes
+    assert len(ops) == N_CLIENTS * per_client + 4  # 2 parks, 2 pushes
+    assert sorted(e["args"]["id"] for e in ops) == list(
+        range(1, len(ops) + 1))
+    kinds = [e["args"]["kind"] for e in ops]
+    assert kinds.count("push") == N_CLIENTS * len(PUSH_LENS) + 2
+    assert kinds.count("enroll") == kinds.count("open") == N_CLIENTS
+    assert kinds.count("park") == 2
+    assert [e["args"]["ok"] for e in ops].count(False) == 1
+    for e in ops:
+        a = e["args"]
+        assert e["ph"] == "X" and e["cat"] == "plane"
+        assert a["queue_us"] >= 0 and a["held_us"] >= 0
+        assert a["queue_us"] + a["held_us"] <= e["dur"] + 1e-6
+    batches = {e["args"]["batch"]: e for e in evs
+               if e["name"] == "plane_batch"}
+    enrolls = [e for e in evs if e["name"] == "enroll"]
+    for e in ops:
+        a = e["args"]
+        # the op's service work lies between its dequeue and its result
+        served = {"ts": e["ts"] + a["queue_us"],
+                  "dur": e["dur"] - a["queue_us"] - a["held_us"]}
+        if a["kind"] == "push" and a["ok"]:
+            b = batches[a["batch"]]
+            assert _inside(b, e) and _inside(b, served)
+            assert any(_inside(d, b) for d in evs if d["name"] == "dispatch")
+        if a["kind"] == "enroll":
+            assert sum(_inside(x, served) for x in enrolls) == 1
+
+
+def _children(evs, parent, names):
+    """Each ``parent`` span with the ``names`` spans inside it, in order."""
+    out = []
+    for p in (e for e in evs if e["name"] == parent and e["ph"] == "X"):
+        kids = sorted((e for e in evs if e["name"] in names and e is not p
+                       and _inside(e, p)), key=lambda e: e["ts"])
+        out.append([k["name"] for k in kids])
+    return out
+
+
+def test_service_phases_nest_inside_dispatch_and_enroll():
+    _, _, evs = _plane_run(Tracer(enabled=True))
+    names = {e["name"] for e in evs}
+    assert {"prepare", "assemble", "reset", "bank_park",
+            "bank_unpark"} <= names
+    dispatches = _children(evs, "dispatch", ("launch", "device_wait", "fetch"))
+    assert len(dispatches) >= N_CLIENTS * len(PUSH_LENS) // 4
+    assert all(k == ["launch", "device_wait", "fetch"] for k in dispatches)
+    enrolls = _children(evs, "enroll", ("embed", "bank_update", "device_wait"))
+    assert len(enrolls) == N_CLIENTS
+    assert all(k == ["embed", "bank_update", "device_wait"] for k in enrolls)
+    # one prepare span per tick, each ending before its tick's dispatch
+    n_dispatch = sum(e["name"] == "dispatch" for e in evs)
+    assert sum(e["name"] == "prepare" for e in evs) == n_dispatch
+    assert sum(e["name"] == "launch" for e in evs) == n_dispatch
+
+
+def test_tracing_leaves_states_and_results_bit_identical():
+    res_on, svc_on, evs = _plane_run(Tracer(enabled=True))
+    res_off, svc_off, none = _plane_run(Tracer())
+    assert evs and none == []
+    for a, b in zip(res_on, res_off):
+        assert a[:2] == b[:2]                      # session, enrolled way
+        assert len(a) == len(b)
+        for ra, rb in zip(a[2:], b[2:]):
+            assert ra["pred"] == rb["pred"] and ra["step"] == rb["step"]
+            for k in ("emb", "logits", "tenant_logits"):
+                np.testing.assert_array_equal(ra[k], rb[k])
+    for la, lb in zip(jax.tree.leaves(svc_on.states),
+                      jax.tree.leaves(svc_off.states)):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    for k in ("s_sums", "counts"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(svc_on.bankpool, k)),
+            np.asarray(getattr(svc_off.bankpool, k)))
