@@ -27,6 +27,7 @@ KV-cache token chunk is the exact analog of the time chunk here.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -139,14 +140,42 @@ def stream_scan_single(params, bn_state, cfg: ArchConfig, state: dict,
 # Fused kernel fast path: whole-chunk block evaluation over ring-buffer taps
 # ---------------------------------------------------------------------------
 
+def _block_rows(n):
+    """Rows a re-lay moves per gather index: B = gcd(n, 128).  On a TPU the
+    ring axis lies along the 128 lanes, and a block of at most one lane row
+    per index stays a single gather, where a longer slice is lowered to a
+    loop over slots with several device ops a trip."""
+    return math.gcd(n, 128)
+
+
+def _gather_blocks(x, starts, B):
+    """result[s, k*B + j] = x[s, starts[s, k] + j] for j < B, each channel's
+    rows moved as one block per index.  x: (S, m, c); starts: (S, nb)."""
+    S, _, c = x.shape
+
+    def take(rows, o):
+        return jax.lax.dynamic_slice_in_dim(rows, o, B)
+
+    per_slot = jax.vmap(jax.vmap(take, (None, 0)), (1, None), 2)
+    return jax.vmap(per_slot)(x, starts).reshape(S, -1, c)
+
+
+def _rotate(x, r):
+    """Per-slot rotation along axis 1: result[s, i] = x[s, (r[s]+i) mod n],
+    for 0 <= r < n.  A block that starts within B rows of the end runs on
+    into a copy of the first B rows."""
+    n = x.shape[1]
+    B = _block_rows(n)
+    starts = (r[:, None] + jnp.arange(0, n, B)) % n
+    return _gather_blocks(jnp.concatenate([x, x[:, :B]], axis=1), starts, B)
+
+
 def _ordered_history(ring, t):
     """Time-order one ring's circular layout.  ring: (S, n, c); t: (S,)
     step counters.  Row i of the result is the sample at time t-n+i —
     slots not yet written (stream younger than n) read their zero init,
     which is exactly causal left-padding."""
-    n = ring.shape[1]
-    idx = (t[:, None] + jnp.arange(n)[None, :]) % n
-    return jnp.take_along_axis(ring, idx[:, :, None], axis=1)
+    return _rotate(ring, t % ring.shape[1])
 
 
 def _ring_advance(strip, t, lengths, n):
@@ -155,12 +184,11 @@ def _ring_advance(strip, t, lengths, n):
     strip: (S, n+T, c) time-ordered [history | chunk-values]; the window
     ``strip[L : L+n]`` holds times t+L-n .. t+L-1, re-laid so slot s holds
     the sample at time ≡ s (mod n).  L=0 reproduces the old ring bit-for-
-    bit (the inactive-slot freeze), with no branch."""
-    ar = jnp.arange(n)[None, :]
-    window = jnp.take_along_axis(strip, (lengths[:, None] + ar)[:, :, None],
-                                 axis=1)
-    perm = (ar - (t + lengths)[:, None]) % n
-    return jnp.take_along_axis(window, perm[:, :, None], axis=1)
+    bit (the inactive-slot freeze), with no branch.  ``t`` is reduced
+    mod n before ``lengths`` is added, so a long stream cannot overflow."""
+    B = _block_rows(n)
+    window = _gather_blocks(strip, lengths[:, None] + jnp.arange(0, n, B), B)
+    return _rotate(window, -(t % n + lengths) % n)
 
 
 def _shard_slots(block_fn, mesh):

@@ -15,6 +15,7 @@ several workers.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,16 +138,28 @@ def _step_args(cfg, T, quantize):
     return fused, states, x, lengths
 
 
+def _row_gathers(text, c):
+    """Gathers that fetch one row of a ``c``-channel ring per index: a
+    slice of 1 along the ring axis and ``c`` along the channels."""
+    return [s for s in re.findall(r"slice_sizes=\{([0-9,]+)\}", text)
+            if sorted(map(int, s.split(","))) == [1, 1, c]]
+
+
 @pytest.mark.parametrize("quantize", [False, True], ids=["fp32", "u4"])
 def test_fused_chunk_step_compiles(one_chip, quantize):
     """One whole fused chunk step of the raw-audio preset at 64 slots:
     the ring re-lay, 12 block kernels and the head, as the service runs
-    it for 10 ms chunks."""
+    it for 10 ms chunks.  The re-lay moves each slot's ring in blocks of
+    rows: no gather fetches single ring rows, and no loop over slots runs
+    (each trip of one would add device ops to every dispatch).  The only
+    Mosaic kernels are the blocks'."""
     cfg = get_config("chameleon-tcn-audio")
     args = _sds(_step_args(cfg, 160, quantize), one_chip)
     step = make_grid_fused(cfg, quantize=quantize, backend="mosaic")
     text = _compiled_text(step, *args, quantize=quantize)
-    assert text.count("tpu_custom_call") >= len(cfg.tcn_channels)
+    assert text.count("tpu_custom_call") == len(cfg.tcn_channels)
+    assert not _row_gathers(text, cfg.tcn_channels[0])
+    assert " while(" not in text
 
 
 def test_fused_chunk_step_compiles_on_mesh(topo, one_chip):

@@ -12,6 +12,8 @@ import pytest
 from _hyp import given, settings, st
 from repro.configs import get_config
 from repro.core.streaming import (
+    _ordered_history,
+    _ring_advance,
     ring_sizes,
     stream_init_single,
     stream_scan_single,
@@ -316,6 +318,49 @@ def test_chunked_push_amortizes_dispatches():
 # Fused kernel fast path: grid executor + service, bit-identical to the
 # pre-existing chunked scan (PR 2's cross-program discipline must survive)
 # ---------------------------------------------------------------------------
+
+def _rows_ordered_history(ring, t):
+    """Row-index reference: row i is ring row (t+i) mod n."""
+    n = ring.shape[1]
+    idx = (t[:, None].astype(np.int64) + np.arange(n)[None, :]) % n
+    return np.take_along_axis(ring, idx[:, :, None], axis=1)
+
+
+def _rows_ring_advance(strip, t, lengths, n):
+    """Row-index reference: window strip[L : L+n], row s of the new ring
+    taking window row (s - t - L) mod n."""
+    ar = np.arange(n)[None, :]
+    window = np.take_along_axis(strip, (lengths[:, None] + ar)[:, :, None],
+                                axis=1)
+    perm = (ar - (t.astype(np.int64) + lengths)[:, None]) % n
+    return np.take_along_axis(window, perm[:, :, None], axis=1)
+
+
+@pytest.mark.parametrize("T", [160, 784])
+@pytest.mark.parametrize("n", [6, 192, 384, 12288])
+def test_ring_relay_bit_exact_vs_row_index(n, T):
+    """The fused step's ring re-lay (per-slot rotations) is the same
+    permutation as indexing every row, bit for bit: time-ordering a ring
+    and laying the advanced window back, for step counters at 0, at
+    t = n-1 (mod n) and near the int32 limit, and lengths 0 .. T."""
+    rng = np.random.default_rng(n * 1000 + T)
+    t = np.array([0, n - 1, 5 * n - 1, 2**31 - 1 - T,
+                  rng.integers(0, 2**30), rng.integers(0, 10 * n)], np.int32)
+    lengths = np.array([0, T, rng.integers(1, T), T, 0,
+                        rng.integers(0, T + 1)], np.int32)
+    S, c = len(t), 3
+    ring = rng.normal(size=(S, n, c)).astype(np.float32)
+    hist = np.asarray(jax.jit(_ordered_history)(ring, t))
+    np.testing.assert_array_equal(hist, _rows_ordered_history(ring, t))
+    strip = np.concatenate(
+        [hist, rng.normal(size=(S, T, c)).astype(np.float32)], axis=1)
+    new = np.asarray(jax.jit(_ring_advance, static_argnums=3)(
+        strip, t, lengths, n))
+    np.testing.assert_array_equal(new,
+                                  _rows_ring_advance(strip, t, lengths, n))
+    frozen = lengths == 0
+    np.testing.assert_array_equal(new[frozen], ring[frozen])
+
 
 @pytest.mark.parametrize("quantize", [False, True])
 def test_fused_chunk_bit_identical_to_grid_scan(quantize):
