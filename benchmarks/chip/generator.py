@@ -17,8 +17,11 @@ A source adds verbs of its own, or replaces these, through a ``VERBS``
 mapping of names to ``async def verb(clients, op)``.
 
 Client ``c`` holds one long-lived session of tenant ``c % tenants``, opened
-in set-up.  Everything a client sends comes from the seed: the same seed
-gives the same sequence of requests, however far a run gets through it.
+in set-up with ``tenant=`` alone, or with the keyword arguments of
+``plane.open_session`` that the source's ``open_kwargs(clients, c)``
+gives where it has one (an LM session's prompt).  Everything a client
+sends comes from the seed: the same seed gives the same sequence of
+requests, however far a run gets through it.
 Every op is logged with its inputs' indices, its timing and what it
 returned, for the correctness check and the metrics.
 """
@@ -106,10 +109,13 @@ class Clients:
         self.log: list[list[Op]] = [[] for _ in range(self.n)]
         self.keep_every = int(mix.get("full_every", 0))
         self.verbs = {**VERBS, **getattr(source, "VERBS", {})}
+        self.deadline: float | None = None    # the window's, once it runs
 
     async def open_all(self):
-        self.sessions = [await self.plane.open_session(tenant=t)
-                         for t in self.tenant]
+        kwargs = getattr(self.source, "open_kwargs",
+                         lambda clients, c: {"tenant": clients.tenant[c]})
+        self.sessions = [await self.plane.open_session(**kwargs(self, c))
+                         for c in range(self.n)]
 
     def keep_full(self, op: Op) -> bool:
         """Whether to keep a push's whole result: a seeded 1-in-
@@ -147,7 +153,7 @@ class Clients:
         """Loop every client until ``seconds`` have passed, then let the
         requests in flight finish.  Returns (start, end of the last op)."""
         start = time.perf_counter()
-        deadline = start + seconds
+        deadline = self.deadline = start + seconds
 
         async def loop(c):
             while time.perf_counter() < deadline:

@@ -5,10 +5,16 @@ metric or check is a file found by its name in ``BENCHMARK.json``:
 
   configs/<config>.json        sizes, serving path and precision (the
                                configuration's ``file`` key)
+  services/<service>.py        the system under test, named by the
+                               configuration's ``"service"`` (absent:
+                               ``tcn_stream``): ``arch_config(config)``,
+                               ``weights(cfg, model, seed)`` -> (params,
+                               what else the check reads), ``build(run,
+                               cfg, tracer, registry)`` -> the service
   traffic/<traffic>.json       the mix, read by ``generator.py``
   sources/<inputs>.py          the kind of inputs a mix names: ``Source``
                                makes them from the seed, ``setup`` fills
-                               the banks
+                               the banks or caches the cell starts with
   layer_metrics/<metric>.py    ``read(ctx) -> float | None`` (or a value
                                and extra keys for the metric, such as
                                the roofline's ``bound``)
@@ -129,6 +135,17 @@ def source_module(p: Plan):
     return load_module(p.root / BENCH / "sources" / f"{p.mix['inputs']}.py")
 
 
+DEFAULT_SERVICE = "tcn_stream"
+
+
+def service_name(p: Plan) -> str:
+    return p.config.get("service", DEFAULT_SERVICE)
+
+
+def service_module(p: Plan):
+    return load_module(p.root / BENCH / "services" / f"{service_name(p)}.py")
+
+
 # ---------------------------------------------------------------------------
 # Spans: the program's tracer, mirrored into the profiler's trace
 # ---------------------------------------------------------------------------
@@ -213,44 +230,6 @@ def check_devices(chips: int, require_tpu: bool = True) -> list:
     return devices
 
 
-def arch_config(config: dict):
-    from repro.configs import get_config
-    model = dict(config["model"])
-    model["tcn_channels"] = tuple(model["tcn_channels"])
-    return get_config(config["preset"]).replace(**model)
-
-
-def build_service(run: Run, cfg, tracer, registry):
-    """The service under test.  A mix with ``"mesh": [data, model]`` shards
-    the slot grid over ``data`` and the tenants' banks over ``model``."""
-    from repro.models import build_bundle
-    from repro.sessions import StreamSessionService
-    mix, serving = run.plan.mix, run.plan.config["serving"]
-    mesh = None
-    if "mesh" in mix:
-        from repro.launch.mesh import make_mesh
-        mesh = make_mesh(tuple(mix["mesh"]), ("data", "model"))
-    return StreamSessionService(
-        build_bundle(cfg), run.params, run.bn, n_slots=mix["slots"],
-        max_tenants=mix["tenants"], max_ways=mix["ways"],
-        t_chunk=mix["chunk"], quantize=serving["quantize"],
-        fused=serving["fused"], paged_bank=serving["paged_bank"],
-        bank_block_ways=serving["bank_block_ways"], metrics=registry,
-        tracer=tracer, mesh=mesh)
-
-
-def _same_layout(params, cfg) -> None:
-    """The benchmark's weights have the tree and shapes of the program's."""
-    import jax
-    from repro.models import build_bundle
-    want = jax.eval_shape(build_bundle(cfg).init, jax.random.key(0))
-    got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-    if jax.tree.structure(want) != jax.tree.structure(got) or any(
-            a.shape != b.shape for a, b in zip(jax.tree.leaves(want),
-                                                jax.tree.leaves(got))):
-        raise ValueError("benchmark weights do not match the program's layout")
-
-
 def execute(run: Run, *, require_tpu: bool = True, service_hook=None) -> Run:
     """Set up, measure the window, free the program's state.  Fills
     ``run`` with the ops log, counters, spans and (traced) trace summary.
@@ -261,7 +240,7 @@ def execute(run: Run, *, require_tpu: bool = True, service_hook=None) -> Run:
     from repro.obs.metrics import MetricsRegistry
     from repro.serving import ServingPlane
 
-    from benchmarks.chip import generator, reference, trace_reduce
+    from benchmarks.chip import generator, trace_reduce
 
     p = run.plan
     run.devices = check_devices(p.workload["chips"], require_tpu)
@@ -270,14 +249,14 @@ def execute(run: Run, *, require_tpu: bool = True, service_hook=None) -> Run:
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     run.model = p.config["model"]
-    cfg = arch_config(p.config)
-    run.params, run.bn = reference.init_params(run.model, run.seed)
-    _same_layout(run.params, cfg)
+    service = service_module(p)
+    cfg = service.arch_config(p.config)
+    run.params, run.bn = service.weights(cfg, run.model, run.seed)
     run.source = source_module(p).Source(p.mix, run.model, run.seed)
     run.phase("weights_and_inputs")
     registry = MetricsRegistry()
     tracer = bench_tracer(run.trace)
-    svc = build_service(run, cfg, tracer, registry)
+    svc = service.build(run, cfg, tracer, registry)
     if service_hook is not None:
         svc = service_hook(svc)
     run.phase("service")
@@ -347,10 +326,22 @@ def _pct(xs, q):
     return float(np.percentile(np.asarray(xs, np.float64), q))
 
 
+def generated_tokens(ops) -> int | None:
+    """Tokens the ops returned, where they return tokens (an LM's)."""
+    toks = [len(op.result["tokens"]) for op in ops if "tokens" in op.result]
+    return sum(toks) if toks else None
+
+
 def end_to_end(run: Run) -> dict:
-    """The cell's end-to-end metrics, over all the work of the window."""
+    """The cell's end-to-end metrics, over all the work of the window.
+    Each is left out where the window's ops carry nothing it reads: the
+    samples of a push (``n``), the tokens an op returned (``tokens``), the
+    delay of a session's first token (``ttft_s``)."""
     pushes = [op for op in run.clients.window_ops("push") if op.ok]
     enrolls = [op for op in run.clients.window_ops("enroll") if op.ok]
+    done = [op for op in run.clients.window_ops() if op.ok]
+    firsts = [op.result["ttft_s"] for op in done if "ttft_s" in op.result]
+    tokens = generated_tokens(done)
     wall = run.end - run.start
     sr = run.plan.config.get("sample_rate")
     values = {
@@ -361,6 +352,8 @@ def end_to_end(run: Run) -> dict:
                           if pushes else None),
         "enroll_p95_ms": (_pct([op.t1 - op.t0 for op in enrolls], 95) * 1e3
                           if enrolls else None),
+        "ttft_p95_ms": _pct(firsts, 95) * 1e3 if firsts else None,
+        "tokens_per_s": tokens / wall if tokens else None,
     }
     return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in run.plan.end_to_end if values.get(m["name"]) is not None}
@@ -377,8 +370,9 @@ class LayerContext:
     spans: list               # the program's spans in the window
     counters: tuple           # registry snapshots at the window's ends
     trace: dict | None        # trace_reduce.reduce() of the window
-    samples: int              # samples in every push result of the window
-    n_ways: int
+    samples: int | None       # samples in every push result of the window
+    n_ways: int | None        # the mix's ways (None: the mix has none)
+    tokens: int | None        # tokens the window's ops returned
 
     def spans_named(self, name: str) -> list:
         return [e for e in self.spans if e.get("name") == name
@@ -398,13 +392,16 @@ def per_layer(run: Run) -> dict:
     from benchmarks.chip import flops
     p = run.plan
     pushes = [op for op in run.clients.window_ops("push") if op.ok]
+    sized = [op.result["n"] for op in pushes if "n" in op.result]
     ctx = LayerContext(
         model=run.model, mix=p.mix, chips=p.workload["chips"],
         peaks=flops.peaks(run.devices[0].device_kind)
         if run.devices[0].platform == "tpu" else None,
         window_s=run.end - run.start, spans=run.spans,
         counters=run.counters, trace=run.trace_summary,
-        samples=sum(op.result["n"] for op in pushes), n_ways=p.mix["ways"])
+        samples=sum(sized) if sized else None, n_ways=p.mix.get("ways"),
+        tokens=generated_tokens(
+            op for op in run.clients.window_ops() if op.ok))
     out = {}
     units = {m["name"]: m["unit"] for m in p.per_layer}
     for name, mod in readers(p).items():

@@ -1,6 +1,11 @@
 """A tiny copy of the benchmark for the CPU: the committed ``BENCHMARK.json``
 and harness files, with the configurations and traffic mixes shrunk so
-that one run of a cell takes seconds.  The limits stay as committed."""
+that one run of a cell takes seconds.  The limits stay as committed.
+
+The CPU sizes are files found by name: ``tiny/config/<config>.json`` for
+each configuration (merged into its ``model``) and
+``tiny/inputs/<inputs>.json`` for each mix (merged into the mix, by the
+kind of inputs it names).  A new cell brings its own."""
 
 from __future__ import annotations
 
@@ -17,21 +22,12 @@ for p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, p)
 
 BENCH = Path("benchmarks") / "chip"
+TINY = Path(__file__).resolve().parent / "tiny"
 
-TINY_MODEL = {
-    "chameleon-tcn-audio": {"tcn_channels": [8, 8, 8, 8], "embed_dim": 16,
-                            "n_layers": 8},
-    "chameleon-tcn": {"tcn_channels": [8, 8, 8], "embed_dim": 16,
-                      "n_layers": 6},
-}
-TINY_MIX = {
-    "keyword_audio": {"slots": 8, "clients": 8, "tenants": 2, "ways": 3,
-                      "shot_samples": 480, "chunk": 16, "full_every": 4,
-                      "ref_block": 4},
-    "glyphs": {"slots": 4, "clients": 2, "tenants": 2, "ways": 20,
-               "shots": 3, "prefill_shots": 2, "pool": 8, "full_every": 2,
-               "ref_block": 16},
-}
+
+def tiny(kind: str, name: str) -> dict:
+    """The CPU sizes of configuration or inputs ``name``."""
+    return json.loads((TINY / kind / f"{name}.json").read_text())
 
 
 def _update(path: Path, **kw) -> None:
@@ -47,10 +43,10 @@ def make(dst: Path) -> Path:
     shutil.copy(ROOT / "BENCHMARK.json", dst)
     bench = json.loads((dst / "BENCHMARK.json").read_text())
     for c in bench["configs"]:
-        _update(dst / c["file"], **TINY_MODEL[c["name"]])
+        _update(dst / c["file"], **tiny("config", c["name"]))
     for w in bench["workloads"]:
         mix = dst / BENCH / "traffic" / f"{w['traffic']}.json"
-        _update(mix, **TINY_MIX[json.loads(mix.read_text())["inputs"]])
+        _update(mix, **tiny("inputs", json.loads(mix.read_text())["inputs"]))
     return dst
 
 
